@@ -227,10 +227,7 @@ def _run_captured(program: str, nproc: int, n: int, iters: int,
 def _roofline_row(program: str, ex, n: int, nproc: int) -> Dict:
     """Achieved-vs-peak report for the captured program: lower+compile
     the scan from its stored avals and walk the HLO cost model."""
-    low = getattr(ex, "last_program_lowered", lambda: None)()
-    if low is None:
-        return {}
-    compiled, meta = low
+    compiled, meta = ex.last_program_lowered()
     steps_covered = meta.get("reps", 1) * meta.get("steps", 1)
     try:
         from repro.roofline.analysis import analyze

@@ -115,7 +115,10 @@ def main(quick: bool = False) -> dict:
     from repro.serve import ServeConfig
 
     bundle, params = _model()
-    scfg = ServeConfig(max_seq=64, slots=2, prefix_reuse=True)
+    # 2-token prefill chunks: the 10-token family prefixes are whole
+    # chunks, so prefix reuse can copy all of them
+    scfg = ServeConfig(max_seq=64, slots=2, prefix_reuse=True,
+                       prefill_chunk=2)
     prompts = _workload(bundle.cfg.vocab, quick)
     replica_counts = [1, 2] if quick else [1, 2, 4]
 

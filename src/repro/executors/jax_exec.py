@@ -130,6 +130,19 @@ def _reduce_identity(op: str, dtype: np.dtype):
     return dtype.type(info.min) if op == "max" else dtype.type(info.max)
 
 
+def cpu_host_platform() -> bool:
+    """True on the XLA *cpu* host platform, where multi-collective plans
+    run staged (one dispatch per collective) and kernel-only steps run
+    per shard — see :meth:`JaxExecutor._build_plan_program` and
+    :meth:`JaxExecutor._build_pershard_kernel`.  Elsewhere a plan or a
+    step is ONE shard_map program and kernels sweep by ``lax.switch``.
+    Every such branch asks this one predicate, so a CPU test can
+    patch it to run the accelerator program shapes."""
+    import jax
+
+    return jax.default_backend() == "cpu"
+
+
 def _decompose_rounds(msgs: Sequence[Msg], nproc: int) -> List[List[Msg]]:
     """Decompose a message list into rounds in which every rank sends
     and receives at most once — each round a valid ``ppermute``
@@ -234,6 +247,15 @@ class JaxExecutor(SimExecutor):
         (one h2d when the host side is newer; no-op otherwise)."""
         with self._lock:
             self._to_device(arr)
+
+    def shard_devices(self, arr: "HDArray") -> list:
+        """The devices holding ``arr``'s resident shards, in rank order
+        (the host copy is staged up first when it is newer)."""
+        with self._lock:
+            self._to_device(arr)
+            shards = self._device[arr.name].addressable_shards
+            return [s.device for s in
+                    sorted(shards, key=lambda s: s.index[0].start or 0)]
 
     def _to_host(self, name: str) -> None:
         if self._host_ok.get(name, True):
@@ -457,12 +479,10 @@ class JaxExecutor(SimExecutor):
         import jax
         from jax.sharding import PartitionSpec as P
 
-        from repro import compat
-
         axis = self.axis
         per_group, counts = self._lower_groups(groups)
         n_coll = sum(len(s) for s in per_group)
-        if n_coll > 1 and jax.default_backend() == "cpu":
+        if n_coll > 1 and cpu_host_platform():
             stages = []
             for gi, steps in enumerate(per_group):
                 for collect, apply in steps:
@@ -470,7 +490,7 @@ class JaxExecutor(SimExecutor):
                         idx = jax.lax.axis_index(axis)
                         x = xb[0]
                         return _a(x, _c(x, idx), idx)[None]
-                    stages.append((gi, jax.jit(compat.shard_map(
+                    stages.append((gi, jax.jit(jax.shard_map(
                         body1, mesh=self._mesh, in_specs=P(axis),
                         out_specs=P(axis), check_vma=False),
                         donate_argnums=(0,))))
@@ -492,7 +512,7 @@ class JaxExecutor(SimExecutor):
             return tuple(outs)
 
         k = len(groups)
-        fn = jax.jit(compat.shard_map(
+        fn = jax.jit(jax.shard_map(
             body, mesh=self._mesh,
             in_specs=tuple(P(axis) for _ in range(k)),
             out_specs=tuple(P(axis) for _ in range(k)),
@@ -732,7 +752,7 @@ class JaxExecutor(SimExecutor):
                 hash((kernel, kw_key))
             except TypeError:
                 kw_key = None      # unhashable kw: trace fresh each call
-            pershard = jax.default_backend() == "cpu"
+            pershard = cpu_host_platform()
             key = ("kernelps" if pershard else "kernel", kernel, kw_key,
                    tuple(r.bounds for r in part_regions),
                    tuple((a.name, a.shape, a.dtype.str) for a in arrays))
@@ -778,8 +798,6 @@ class JaxExecutor(SimExecutor):
         import jax
         from jax.sharding import PartitionSpec as P
 
-        from repro import compat
-
         names = [a.name for a in arrays]
         regions = list(part_regions)
         axis = self.axis
@@ -809,7 +827,7 @@ class JaxExecutor(SimExecutor):
             return tuple(o[None] for o in out)
 
         donate = tuple(i for i, n in enumerate(names) if n in defined)
-        fn = jax.jit(compat.shard_map(
+        fn = jax.jit(jax.shard_map(
             body, mesh=self._mesh,
             in_specs=tuple(P(axis) for _ in names),
             out_specs=tuple(P(axis) for _ in out_names),
@@ -940,7 +958,7 @@ class JaxExecutor(SimExecutor):
 
         from .overlap import halo_split
 
-        if not groups and jax.default_backend() == "cpu":
+        if not groups and cpu_host_platform():
             # no traffic (e.g. GEMM after the gather): the kernel alone
             # is the step, and per-shard dispatch beats the one-program
             # switch on the cpu backend (see _build_pershard_kernel)
@@ -1102,8 +1120,6 @@ class JaxExecutor(SimExecutor):
         import jax
         from jax.sharding import PartitionSpec as P
 
-        from repro import compat
-
         axis = self.axis
         names = [a.name for a in arrays]
         regions = list(part_regions)
@@ -1117,7 +1133,7 @@ class JaxExecutor(SimExecutor):
         out_names = [n for n in names if n in defined or n in traffic]
         launches = 1 if out_kernel else 0
 
-        if n_coll > 1 and jax.default_backend() == "cpu":
+        if n_coll > 1 and cpu_host_platform():
             stages = []
             for gi, steps in zip(gidx, per_group):
                 for collect, apply in steps:
@@ -1125,7 +1141,7 @@ class JaxExecutor(SimExecutor):
                         idx = jax.lax.axis_index(axis)
                         x = xb[0]
                         return _a(x, _c(x, idx), idx)[None]
-                    stages.append((gi, jax.jit(compat.shard_map(
+                    stages.append((gi, jax.jit(jax.shard_map(
                         body1, mesh=self._mesh, in_specs=P(axis),
                         out_specs=P(axis), check_vma=False),
                         donate_argnums=(0,))))
@@ -1146,7 +1162,7 @@ class JaxExecutor(SimExecutor):
             return tuple(xs[names.index(n)][None] for n in out_names)
 
         donate = tuple(i for i, n in enumerate(names) if n in out_names)
-        fn = jax.jit(compat.shard_map(
+        fn = jax.jit(jax.shard_map(
             body, mesh=self._mesh,
             in_specs=tuple(P(axis) for _ in names),
             out_specs=tuple(P(axis) for _ in out_names),
@@ -1248,8 +1264,6 @@ class JaxExecutor(SimExecutor):
         import jax
         from jax.sharding import PartitionSpec as P
 
-        from repro import compat
-
         axis = self.axis
         names = [a.name for a in union]
         counts = {"all_gather": 0, "all_to_all": 0, "ppermute": 0}
@@ -1291,7 +1305,7 @@ class JaxExecutor(SimExecutor):
                                   length=reps)
             return tuple(o[None] for o in out)
 
-        fn = jax.jit(compat.shard_map(
+        fn = jax.jit(jax.shard_map(
             body, mesh=self._mesh,
             in_specs=tuple(P(axis) for _ in names),
             out_specs=tuple(P(axis) for _ in names),
@@ -1305,15 +1319,15 @@ class JaxExecutor(SimExecutor):
     def last_program_lowered(self):
         """Compile the most recent fused step / captured scan program
         from its stored avals and return ``(compiled, meta)`` — the
-        input of the roofline report in benchmarks/executor_residency.
-        Returns None when nothing was captured or lowering fails."""
+        input of the roofline report in benchmarks/executor_residency
+        and of the chip smoke's ``tpu_custom_call`` check.  Raises
+        RuntimeError when no such program was built yet; a failing
+        compile raises what the compiler raised."""
         if self._last_program is None:
-            return None
+            raise RuntimeError("no fused step or captured scan program "
+                               "has been built on this executor")
         fn, avals, meta = self._last_program
-        try:
-            return fn.lower(*avals).compile(), meta
-        except Exception:
-            return None
+        return fn.lower(*avals).compile(), meta
 
     # -- reductions -----------------------------------------------------
     def reduce_local(self, arr: "HDArray", per_device, op: str):
@@ -1357,7 +1371,6 @@ class JaxExecutor(SimExecutor):
         import jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
 
-        from repro import compat
         # the op -> collective-name table is shared with the symbolic
         # lowering (function-level import: core.comm imports executors)
         from repro.core.comm import REDUCE_COLLECTIVES
@@ -1376,7 +1389,7 @@ class JaxExecutor(SimExecutor):
                 r = prims[op](v, axis)
             return r[None]
 
-        fn = jax.jit(compat.shard_map(
+        fn = jax.jit(jax.shard_map(
             body, mesh=self._mesh, in_specs=P(axis), out_specs=P(axis),
             check_vma=False))
         return fn, {REDUCE_COLLECTIVES[op]: 1}

@@ -16,9 +16,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu  # noqa: F401
-
-from repro import compat
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _gemm_kernel(a_ref, b_ref, o_ref, acc_ref, *, nk: int, alpha: float):
@@ -63,7 +61,7 @@ def gemm_pallas(a, b, *, alpha: float = 1.0, block_m: int = 256,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((Mp, Np), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(a, b)

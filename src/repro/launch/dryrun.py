@@ -35,7 +35,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro import compat
 from repro.configs import SHAPES, all_configs, get_config
 from repro.launch.mesh import make_production_mesh
 from repro.models import build
@@ -161,7 +160,7 @@ def lower_cell(arch: str, shape_name: str, multi_pod: bool,
     rec["train_cfg"] = dataclasses.asdict(tcfg)
     rec["moment_dtype"] = moment_dtype
 
-    with mesh, compat.set_mesh(mesh):
+    with mesh, jax.sharding.set_mesh(mesh):
         if shape_cell.kind == "train":
             if tcfg.param_dtype == "bf16":
                 params_shape = _cast_shapes(params_shape, jnp.bfloat16)
@@ -220,7 +219,7 @@ def lower_cell(arch: str, shape_name: str, multi_pod: bool,
         mem["error"] = repr(e)
     rec["memory"] = mem
 
-    ca = compat.cost_analysis(compiled)
+    ca = compiled.cost_analysis()
     rec["cost"] = {k: float(v) for k, v in ca.items()
                    if isinstance(v, (int, float)) and
                    k in ("flops", "bytes accessed", "transcendentals",

@@ -17,6 +17,15 @@ Axis roles under the baseline HDArray rules (train/sharding.py):
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto(axes) -> dict:
+    """``axis_types`` for ``jax.make_mesh``: every axis Auto.  The
+    sharding code places arrays with ``NamedSharding`` and
+    ``with_sharding_constraint`` (GSPMD propagation), which Explicit
+    axes — ``jax.make_mesh``'s default — reject."""
+    return {"axis_types": (AxisType.Auto,) * len(axes)}
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -31,7 +40,7 @@ def make_production_mesh(*, multi_pod: bool = False):
             f"mesh {shape} needs {n} devices, found {len(devices)} — "
             "run via launch/dryrun.py, which forces "
             "--xla_force_host_platform_device_count=512")
-    return jax.make_mesh(shape, axes, devices=devices[:n])
+    return jax.make_mesh(shape, axes, devices=devices[:n], **_auto(axes))
 
 
 def make_debug_mesh(shape=(2, 2), axes=("data", "model")):
@@ -39,7 +48,8 @@ def make_debug_mesh(shape=(2, 2), axes=("data", "model")):
     n = 1
     for s in shape:
         n *= s
-    return jax.make_mesh(shape, axes, devices=jax.devices()[:n])
+    return jax.make_mesh(shape, axes, devices=jax.devices()[:n],
+                         **_auto(axes))
 
 
 # ----------------------------------------------------------------------
@@ -87,4 +97,5 @@ def make_host_mesh(nproc: int, axis: str = "p"):
             "set XLA_FLAGS=--xla_force_host_platform_device_count="
             f"{nproc} before the first jax init (see "
             "launch.mesh.ensure_host_devices)")
-    return jax.make_mesh((nproc,), (axis,), devices=devices[:nproc])
+    return jax.make_mesh((nproc,), (axis,), devices=devices[:nproc],
+                         **_auto((axis,)))

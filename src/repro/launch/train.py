@@ -166,6 +166,8 @@ def main():
     ap.add_argument("--grad-compress", default="none",
                     choices=["none", "bf16", "int8"])
     args = ap.parse_args()
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     run = setup(args.arch, reduced=not args.full, seq_len=args.seq,
                 global_batch=args.batch, microbatches=args.microbatches,
                 lr=args.lr, ckpt_dir=args.ckpt_dir, total_steps=args.steps,

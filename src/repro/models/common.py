@@ -75,9 +75,8 @@ def constrain_dims(x, spec_map):
     regression the roofline walker caught (EXPERIMENTS.md §Perf)."""
     from jax.sharding import PartitionSpec as P
 
-    from repro import compat
 
-    m = compat.get_abstract_mesh()
+    m = jax.sharding.get_abstract_mesh()
     if m is None or not m.shape:
         return x
     spec = [P.UNCONSTRAINED] * x.ndim
@@ -106,7 +105,6 @@ def sharded_batch_update(cache, new, pos):
     per decode step on the 32k cells; §Perf iteration 7)."""
     from jax.sharding import PartitionSpec as P
 
-    from repro import compat
 
     def upd(c, n, p):
         return jax.lax.dynamic_update_slice(
@@ -115,7 +113,7 @@ def sharded_batch_update(cache, new, pos):
     def local(c, n, p):
         return jax.vmap(upd)(c, n, p)
 
-    mesh = compat.get_abstract_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     if mesh is None or not mesh.shape:
         return local(cache, new, pos)
     baxes = tuple(a for a in BATCH_AXES if a in mesh.shape)
@@ -129,17 +127,16 @@ def sharded_batch_update(cache, new, pos):
             and cache.shape[-1] >= nm else None)
     spec_c = P(b, *([None] * (cache.ndim - 2)), last)
     spec_n = P(b, *([None] * (new.ndim - 2)), last)
-    return compat.shard_map(local, mesh=mesh,
-                            in_specs=(spec_c, spec_n, P(b)),
-                            out_specs=spec_c, check_vma=False)(cache, new, pos)
+    return jax.shard_map(local, mesh=mesh,
+                         in_specs=(spec_c, spec_n, P(b)),
+                         out_specs=spec_c, check_vma=False)(cache, new, pos)
 
 
 def constrain_attention_blocks(x, batch_dim, head_dims):
     """Batch dim over the data axes; first divisible head dim over
     'model'."""
     m = {batch_dim: BATCH_AXES}
-    from repro import compat
-    mesh = compat.get_abstract_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     if mesh is not None and "model" in mesh.shape:
         n = mesh.shape["model"]
         for d in head_dims:

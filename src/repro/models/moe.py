@@ -23,7 +23,6 @@ from typing import Dict, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 
 
 def moe_params(key, d_model: int, mo, n_layers: int) -> Tuple[Dict, Dict]:
@@ -127,7 +126,7 @@ def moe_ffn(p, x, mo, *, impl: str = "auto") -> Tuple[jax.Array, jax.Array]:
                    context (dry-run/launchers), else 'sort' (CPU tests).
     """
     if impl == "auto":
-        m = compat.get_abstract_mesh()
+        m = jax.sharding.get_abstract_mesh()
         ok = (m is not None and "model" in m.shape
               and mo.num_experts % m.shape["model"] == 0)
         impl = "ep" if ok else "sort"
@@ -149,7 +148,7 @@ def moe_ffn(p, x, mo, *, impl: str = "auto") -> Tuple[jax.Array, jax.Array]:
 def _moe_ffn_ep(p, x, mo) -> Tuple[jax.Array, jax.Array]:
     """Expert-parallel path (see moe_ffn docstring)."""
     from jax.sharding import PartitionSpec as P
-    mesh = compat.get_abstract_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     nm = mesh.shape["model"]
     batch_axes = tuple(a for a in ("pod", "data") if a in mesh.shape)
     nb = 1
@@ -195,6 +194,6 @@ def _moe_ffn_ep(p, x, mo) -> Tuple[jax.Array, jax.Array]:
             shared_p.get("w_gate", jnp.zeros((D, nm), x.dtype)),
             shared_p.get("w_up", jnp.zeros((D, nm), x.dtype)),
             shared_p.get("w_down", jnp.zeros((nm, D), x.dtype)))
-    out, aux = compat.shard_map(body, mesh=mesh, in_specs=in_specs,
-                                out_specs=out_specs, check_vma=False)(*args)
+    out, aux = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                             out_specs=out_specs, check_vma=False)(*args)
     return out, aux

@@ -1,6 +1,7 @@
 """Roofline-term derivation from the compiled dry-run artifact.
 
-This container is CPU-only — TPU v5e is the TARGET, not the runtime —
+These terms come from the compiled program, not from a run: the
+dry-run compiles for placeholder devices without executing anything,
 so the three roofline terms are derived structurally:
 
     compute    = HLO_FLOPs_per_device  / PEAK_FLOPS

@@ -21,14 +21,24 @@ by `examples/serve_lm.py`:
   * cancellation: ``cancel(ticket)`` removes a queued request;
     ``cancel(slot)`` aborts a live decode, frees the slot, and
     backfills it from the admission queue,
+  * chunked prefill (with prefix reuse on, or ``prefill_chunk`` set):
+    a prompt is prefilled in calls over the fixed position blocks
+    [0, C), [C, 2C), ..., so the programs that compute a token's KV
+    and logits never depend on how much of the prompt was already
+    cached.  On TPU a token's numerics depend on the shape of the
+    call it runs in, so this is what keeps prefix reuse bit-identical
+    there.  Otherwise a prompt is prefilled in one call,
   * prefix reuse (``ServeConfig(prefix_reuse=True)``): when another
     slot's cache rows start with a prefix of the new prompt, the
-    matched rows are copied (KV at position i is a pure function of
-    tokens[0..i] under causal attention, so the copy is bit-identical
-    to recomputing) and only the suffix is prefilled — the
-    router-visible "prefill work" drops by the matched length.  Only
-    cache families with a per-position seq axis support this (full KV,
-    MLA latent); ring/recurrent families auto-disable,
+    matched rows that a whole C-token chunk call wrote are copied (KV
+    at position i is a pure function of tokens[0..i] under causal
+    attention, and those rows came from the same chunk programs, so
+    the copy is bit-identical to recomputing) and only the remaining
+    chunks are prefilled — the router-visible "prefill work" drops by
+    the copied length.  Rows of a ragged last chunk or of decode steps
+    came from other call shapes and are never copied.  Only cache
+    families with a per-position seq axis support this (full KV, MLA
+    latent); ring/recurrent families auto-disable,
   * failover: :class:`RecoveryEngine` backs the slot KV caches with
     HDArrays partitioned over serving instances (ranks), so an
     instance loss mid-request is the ft layer's planned shrink — KV
@@ -60,6 +70,11 @@ class SlotsExhausted(RuntimeError):
     working."""
 
 
+# prompt tokens per prefill call when prefix reuse is on and
+# ServeConfig.prefill_chunk is not set
+REUSE_PREFILL_CHUNK = 64
+
+
 @dataclasses.dataclass(frozen=True)
 class ServeConfig:
     max_seq: int = 2048         # cache capacity per slot
@@ -68,6 +83,21 @@ class ServeConfig:
     top_k: int = 0              # 0 => full softmax
     queue_depth: int = 0        # admission queue size (0 => reject)
     prefix_reuse: bool = False  # copy matching cached prefix rows on admit
+    # prompt tokens per prefill call; None: the whole prompt in one
+    # call, or REUSE_PREFILL_CHUNK-token chunks with prefix_reuse on
+    prefill_chunk: Optional[int] = None
+
+    def __post_init__(self):
+        if self.prefill_chunk is not None and self.prefill_chunk < 1:
+            raise ValueError(f"prefill_chunk must be >= 1, got "
+                             f"{self.prefill_chunk}")
+
+    @property
+    def chunk(self) -> Optional[int]:
+        """The prefill chunk in effect (None: one call per prompt)."""
+        if self.prefill_chunk is None and self.prefix_reuse:
+            return REUSE_PREFILL_CHUNK
+        return self.prefill_chunk
 
 
 def sample_tokens(logits, key, temperature: float = 0.0, top_k: int = 0):
@@ -125,28 +155,24 @@ class Engine:
         self.admitted: Dict[int, int] = {}
         self._next_ticket = -1
         # which axis of each cache leaf is the slot (batch) dim: probed
-        # by re-initializing the cache with one extra slot and diffing
-        # shapes (family-agnostic — full KV, MLA latent, recurrent all
-        # place B differently); -1 marks a slot-invariant leaf
-        probe = bundle.init_cache(scfg.slots + 1, scfg.max_seq)
-        self._slot_axis = jax.tree.map(
-            lambda c, p: next((d for d, (s0, s1)
-                               in enumerate(zip(c.shape, p.shape))
-                               if s0 != s1), -1),
-            self.cache, probe)
-        del probe
+        # by shaping the cache with one extra slot (jax.eval_shape: no
+        # allocation) and diffing shapes (family-agnostic — full KV,
+        # MLA latent, recurrent all place B differently); -1 marks a
+        # slot-invariant leaf
+        def first_diff(c, p):
+            return next((d for d, (s0, s1) in enumerate(zip(c.shape,
+                                                            p.shape))
+                         if s0 != s1), -1)
+
+        self._slot_axis = jax.tree.map(first_diff, self.cache, jax.eval_shape(
+            lambda: bundle.init_cache(scfg.slots + 1, scfg.max_seq)))
         # which axis is the per-position (seq) dim, probed the same way
         # with one extra cache row — prefix reuse copies rows along it.
         # Leaves without one (ring slabs, recurrent state, `pos`) get
         # -1; a slot-carrying non-`pos` leaf with no seq axis means the
         # family folds history into running state, so reuse is off.
-        probe = bundle.init_cache(scfg.slots, scfg.max_seq + 1)
-        self._seq_axis = jax.tree.map(
-            lambda c, p: next((d for d, (s0, s1)
-                               in enumerate(zip(c.shape, p.shape))
-                               if s0 != s1), -1),
-            self.cache, probe)
-        del probe
+        self._seq_axis = jax.tree.map(first_diff, self.cache, jax.eval_shape(
+            lambda: bundle.init_cache(scfg.slots, scfg.max_seq + 1)))
         paths = [jax.tree_util.keystr(path) for path, _ in
                  jax.tree_util.tree_flatten_with_path(self.cache)[0]]
         self.supports_prefix_reuse = all(
@@ -159,6 +185,11 @@ class Engine:
         # until the slot is reused, so finished sequences act as a
         # prefix cache; len(kv_tokens[s]) == slot_pos[s] while live
         self.kv_tokens: List[List[int]] = [[] for _ in range(scfg.slots)]
+        # how many of each slot's leading rows whole C-token prefill
+        # chunks wrote: the only rows prefix reuse may copy
+        self.chunk_rows = np.zeros(scfg.slots, np.int64)
+        # the last admitted prompt's last-position logits (V,), on device
+        self.prefill_logits = None
         # prefill-work accounting for the router/benchmark layer
         self.prefill_tokens_computed = 0
         self.prefix_hits = 0
@@ -230,35 +261,44 @@ class Engine:
                extra_inputs: Optional[Dict[str, Any]]) -> int:
         T = len(prompt_tokens)
         B = self.scfg.slots
-        # prefix reuse: find the slot whose cached rows share the
-        # longest prefix with this prompt, copy those rows, and only
-        # prefill the suffix (L is capped at T-1: the last prompt
-        # token always runs so prefill has logits to return)
+        # chunks need per-position cache rows (the families that support
+        # prefix reuse) and no per-request inputs; otherwise one call
+        chunked = (self.scfg.chunk is not None and self.supports_prefix_reuse
+                   and not extra_inputs)
+        C = self.scfg.chunk if chunked else T
+        # prefix reuse: find the slot whose whole-chunk rows share the
+        # longest prefix with this prompt, copy them, and only prefill
+        # the rest (L is capped at T-1: the last prompt token always
+        # runs so prefill has logits to return)
         L, src = 0, sid
-        if (self.scfg.prefix_reuse and self.supports_prefix_reuse
-                and not extra_inputs):
+        if self.scfg.prefix_reuse and chunked:
             src, L = self._best_prefix(prompt_tokens)
+            L -= L % C
         snapshot = jax.tree.map(lambda x: x, self.cache)
         if L > 0 and src != sid:
             self._copy_prefix_rows(src, sid, L)
-        toks = np.zeros((B, T - L), np.int32)
-        toks[sid] = prompt_tokens[L:]
-        batch = {"tokens": jnp.asarray(toks)}
-        if extra_inputs:
-            batch.update(extra_inputs)
         # snapshot + scatter: prefill traces the WHOLE pool batch, so
         # it rewrites every slot's cache at the prompt positions (and
         # advances every slot's pos).  Keep only the admitted slot's
         # rows; every other live slot's cache is bit-identical to its
         # pre-prefill snapshot.
-        for g in self._cache_groups():
-            g["pos"] = jnp.where(jnp.arange(B) == sid, L, g["pos"])
-        logits, cache = self._prefill(self.params, batch, self.cache)
-        self.cache = self._scatter_slot(snapshot, cache, sid)
+        for lo in range(L, T, C):
+            hi = min(lo + C, T)
+            toks = np.zeros((B, hi - lo), np.int32)
+            toks[sid] = prompt_tokens[lo:hi]
+            batch = {"tokens": jnp.asarray(toks)}
+            if extra_inputs:
+                batch.update(extra_inputs)
+            for g in self._cache_groups():
+                g["pos"] = jnp.where(jnp.arange(B) == sid, lo, g["pos"])
+            logits, cache = self._prefill(self.params, batch, self.cache)
+            self.cache = self._scatter_slot(snapshot, cache, sid)
         self.slot_pos[sid] = T
         self.slot_live[sid] = True
         self.slot_tokens[sid] = list(map(int, prompt_tokens))
         self.kv_tokens[sid] = list(map(int, prompt_tokens))
+        self.chunk_rows[sid] = T - T % C if chunked else 0
+        self.prefill_logits = logits[sid, -1]
         self.prefill_tokens_computed += T - L
         if L > 0:
             self.prefix_hits += 1
@@ -269,14 +309,14 @@ class Engine:
         return sid
 
     def _best_prefix(self, prompt: np.ndarray) -> Tuple[int, int]:
-        """(slot, match length): the slot whose cached token rows share
+        """(slot, match length): the slot whose whole-chunk rows share
         the longest common prefix with `prompt` (live or retained),
         capped at len(prompt)-1.  Ties break to the lowest slot id."""
         best_s, best_l = 0, 0
         cap = len(prompt) - 1
         for s in range(self.scfg.slots):
             cached = self.kv_tokens[s]
-            n = min(cap, len(cached))
+            n = min(cap, int(self.chunk_rows[s]))
             m = 0
             while m < n and cached[m] == int(prompt[m]):
                 m += 1
@@ -378,7 +418,11 @@ class RecoveryEngine:
     Every cache leaf mirrors into one HDArray (slot axis moved to
     dim 0, non-native dtypes bit-viewed); a ``CheckpointManager``
     snapshots the HDArrays + the host slot table after each admit and
-    every ``checkpoint_interval`` decode steps.  ``fail_instance(rank)``
+    every ``checkpoint_interval`` decode steps.  The mirror is written
+    only when a checkpoint is taken or a grow migrates it, not on every
+    decode step: between checkpoints the engine's device cache is the
+    state, and a loss restores from the checkpoint anyway.
+    ``fail_instance(rank)``
     is the ft layer's planned shrink applied to serving: mark the rank
     lost, restore the checkpoint onto the survivors' staging layout,
     ``repartition`` the live slots' caches onto the shrunken layout
@@ -467,7 +511,6 @@ class RecoveryEngine:
         self.rt.planner.stats.note_rank_times(self._decode_count, times)
         self.last_step_time = max(times)
         self._decode_count += 1
-        self._mirror()
         if self._decode_count - self._ckpt_decode >= self.checkpoint_interval:
             self._checkpoint()
         return out
@@ -532,7 +575,6 @@ class RecoveryEngine:
         for _ in range(replay):
             self.engine.step()
             self._decode_count += 1
-            self._mirror()
         self.rt.planner.stats.elastic_shrinks += 1
         self.rt.recovery_log.append({
             "kind": "instance_loss", "rank": rank, "live": list(self.live),
@@ -553,6 +595,9 @@ class RecoveryEngine:
                 "live": list(self.live), "migration_bytes": 0,
                 "noop": True, "plan": None})
             return
+        # the grow migrates the cache as it stands, not as of the last
+        # checkpoint
+        self._mirror()
         self.live.append(rank)
         self.live.sort()
         for arr in self.rt.arrays.values():
@@ -620,6 +665,7 @@ class RecoveryEngine:
             "slot_live": eng.slot_live.copy(),
             "slot_tokens": [list(t) for t in eng.slot_tokens],
             "kv_tokens": [list(t) for t in eng.kv_tokens],
+            "chunk_rows": eng.chunk_rows.copy(),
             "key": eng._key,
             "queue": list(eng.queue),
             "admitted": dict(eng.admitted),
@@ -635,6 +681,7 @@ class RecoveryEngine:
         eng.slot_live = snap["slot_live"].copy()
         eng.slot_tokens = [list(t) for t in snap["slot_tokens"]]
         eng.kv_tokens = [list(t) for t in snap["kv_tokens"]]
+        eng.chunk_rows = snap["chunk_rows"].copy()
         eng._key = snap["key"]
         eng.queue = collections.deque(snap["queue"])
         eng.admitted = dict(snap["admitted"])

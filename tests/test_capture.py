@@ -247,3 +247,126 @@ def test_null_backend_pipeline_metadata_parity():
     assert len(plans) == 10 and all(p is not None for p in plans)
     assert rt.planner.stats.scan_captures == 0
     rt.close()
+
+
+# -- the accelerator program shapes, run on the CPU ----------------------
+# On the XLA cpu host platform the jax executor stages multi-collective
+# plans one collective per dispatch and runs kernel-only steps per
+# shard.  Elsewhere a plan or a step is ONE shard_map program and the
+# kernel sweep is a lax.switch over ranks.  Patching the executor's
+# one predicate runs those accelerator shapes here, against sim.
+@pytest.fixture
+def accelerator_shapes(monkeypatch):
+    from repro.executors import jax_exec
+
+    monkeypatch.setattr(jax_exec, "cpu_host_platform", lambda: False)
+
+
+def _program_modes(ex):
+    """Which program shapes the executor built, by cache-key kind."""
+    modes = set()
+    for key, prog in ex._programs.items():
+        if key[0] == "step":
+            modes.add(prog[0])                 # "fused" | "staged"
+        elif isinstance(key[0], str):
+            modes.add(key[0])                  # kernel | kernelps | scan
+        else:                                  # plan program: stage list
+            modes.add("plan" if prog[0][0][0] is None else "plan_staged")
+    return modes
+
+
+def test_accelerator_fused_halo_steps_bit_identical_to_sim(
+        accelerator_shapes):
+    _need_devices(4)
+    rt_sim = HDArrayRuntime(4, backend="sim")
+    a_s, b_s, log_s = _jacobi_pipeline(rt_sim, steps=12)
+    rt_sim.close()
+    rt = HDArrayRuntime(4, backend="jax")
+    a_j, b_j, log_j = _jacobi_pipeline(rt, steps=12)
+    # both halo ppermutes and the lax.switch sweep in ONE step program,
+    # then the captured scan
+    assert _program_modes(rt.executor) == {"fused", "scan"}
+    assert rt.executor.collective_counts["ppermute"] > 0
+    assert rt.planner.stats.scan_captures >= 1
+    assert np.array_equal(a_s, a_j) and np.array_equal(b_s, b_j)
+    assert log_s == log_j
+    rt.close()
+
+
+@device_kernel
+def _gemm_jnp(region, bufs):
+    import jax.numpy as jnp
+
+    rows = region.to_slices()[0]
+    out = jnp.dot(bufs["A"][rows, :], bufs["B"])
+    return {"C": kernel_put(bufs["C"], (rows, slice(None)), out)}
+
+
+def test_accelerator_gemm_gather_then_kernel_only_steps(accelerator_shapes):
+    _need_devices(4)
+    n = 32
+    rng = np.random.default_rng(9)
+    # small integers: every product and sum is exact in float32, so
+    # the two backends' dots agree bit for bit
+    a = rng.integers(-4, 5, (n, n)).astype(np.float32)
+    b = rng.integers(-4, 5, (n, n)).astype(np.float32)
+    outs = {}
+    for backend in ("sim", "jax"):
+        rt = HDArrayRuntime(4, backend=backend)
+        A, B, C = (rt.create(nm, (n, n)) for nm in ("A", "B", "C"))
+        part = rt.partition_row((n, n))
+        rt.write(A, a, part)
+        rt.write(B, b, part)           # row-partitioned: B is gathered
+        rt.write(C, np.zeros((n, n), np.float32), part)
+        rt.run_pipeline([dict(kernel_name="gemm", part_id=part,
+                              kernel=_gemm_jnp, arrays=[A, B, C],
+                              uses={"A": ROW_ALL, "B": COL_ALL},
+                              defs={"C": IDENTITY_2D})] * 6)
+        outs[backend] = rt.read_coherent(C)
+        if backend == "jax":
+            ex = rt.executor
+            assert ex.collective_counts["all_gather"] >= 1
+            # the gather step and the traffic-free steps are each ONE
+            # program (no per-shard kernel dispatch)
+            assert "kernelps" not in _program_modes(ex)
+            assert "fused" in _program_modes(ex)
+        rt.close()
+    assert np.array_equal(outs["sim"], a @ b)
+    assert np.array_equal(outs["jax"], outs["sim"])
+
+
+def test_accelerator_overlap_and_host_kernel_paths(accelerator_shapes):
+    _need_devices(4)
+
+    def host_jac(region, bufs):            # unmarked: host mirrors
+        (r0, r1), (c0, c1) = region.bounds
+        x = bufs["A"]
+        bufs["B"][r0:r1, c0:c1] = (
+            x[r0:r1, c0 - 1:c1 - 1] + x[r0:r1, c0 + 1:c1 + 1]
+            + x[r0 - 1:r1 - 1, c0:c1] + x[r0 + 1:r1 + 1, c0:c1]) * 0.25
+
+    def host_jac_back(region, bufs):
+        (r0, r1), (c0, c1) = region.bounds
+        x = bufs["B"]
+        bufs["A"][r0:r1, c0:c1] = (
+            x[r0:r1, c0 - 1:c1 - 1] + x[r0:r1, c0 + 1:c1 + 1]
+            + x[r0 - 1:r1 - 1, c0:c1] + x[r0 + 1:r1 + 1, c0:c1]) * 0.25
+
+    rt_sim = HDArrayRuntime(4, backend="sim")
+    ref = _jacobi_pipeline(rt_sim, steps=8)[:2]
+    rt_sim.close()
+    # overlap schedule: messages as one fused plan program, device
+    # kernels as the lax.switch sweep
+    rt = HDArrayRuntime(4, backend="jax", overlap=True)
+    got = _jacobi_pipeline(rt, steps=8)[:2]
+    assert {"plan", "kernel"} <= _program_modes(rt.executor)
+    assert "plan_staged" not in _program_modes(rt.executor)
+    assert all(np.array_equal(r, g) for r, g in zip(ref, got))
+    rt.close()
+    # host kernels: the exchange is one fused plan program
+    rt = HDArrayRuntime(4, backend="jax")
+    got = _jacobi_pipeline(rt, steps=8,
+                           kernels=(host_jac, host_jac_back))[:2]
+    assert _program_modes(rt.executor) == {"plan"}
+    assert all(np.array_equal(r, g) for r, g in zip(ref, got))
+    rt.close()

@@ -40,6 +40,20 @@ def test_jacobi_matches_ref(M, N, dtype):
                                rtol=tol, atol=tol)
 
 
+# arrays wider than one column block, ragged in both axes: the left /
+# right halo tiles, their clamped index maps and the ragged last block
+@pytest.mark.parametrize("M,N", [(100, 300), (37, 530)])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_jacobi_column_blocks_bit_identical(M, N, dtype):
+    rng = np.random.default_rng(3)
+    x = jnp.asarray(rng.standard_normal((M, N)), dtype)
+    got = jacobi_pallas(x, block_m=32, block_n=128, interpret=True)
+    # the kernel sums in float32 and rounds once to the array's dtype
+    want = jacobi_ref(x.astype(jnp.float32)).astype(dtype)
+    np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                  np.asarray(want, np.float32))
+
+
 def test_jacobi_iterated_vs_numpy():
     """Multiple sweeps = the paper's Jacobi benchmark inner loop."""
     rng = np.random.default_rng(2)

@@ -7,7 +7,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro import compat
 from repro.configs.base import MoECfg
 from repro.models import moe as MOE
 
@@ -29,7 +28,7 @@ def test_ep_matches_sort_on_1x1_mesh(shared):
     want, aux_want = MOE.moe_ffn(pl, x, mo, impl="sort")
     mesh = jax.make_mesh((1, 1), ("data", "model"),
                          devices=jax.devices()[:1])
-    with compat.set_mesh(mesh):
+    with jax.sharding.set_mesh(mesh):
         got, aux_got = jax.jit(
             lambda p_, x_: MOE.moe_ffn(p_, x_, mo, impl="auto"))(pl, x)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
@@ -58,7 +57,7 @@ def test_ep_grads_match_sort():
     g1 = jax.grad(loss_sort)(pl, x)
     mesh = jax.make_mesh((1, 1), ("data", "model"),
                          devices=jax.devices()[:1])
-    with compat.set_mesh(mesh):
+    with jax.sharding.set_mesh(mesh):
         g2 = jax.jit(jax.grad(loss_ep))(pl, x)
     for a, b in zip(jax.tree.leaves(g1), jax.tree.leaves(g2)):
         np.testing.assert_allclose(np.asarray(b), np.asarray(a),

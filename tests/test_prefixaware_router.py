@@ -8,6 +8,7 @@ import pytest
 from repro.serve import (Engine, PrefixAwareRouter, ReplicaPool,
                          ReplicaView, RoundRobinRouter, ServeConfig,
                          TokenTrie, get_router)
+from repro.serve.engine import REUSE_PREFILL_CHUNK
 
 
 # ----------------------------------------------------------------------
@@ -109,13 +110,15 @@ def test_engine_prefix_reuse_bit_identical_and_cheaper(serve_model):
     p1 = np.concatenate([shared, rng.integers(0, V, 4)])
     p2 = np.concatenate([shared, rng.integers(0, V, 6)])
 
-    ref = Engine(bundle, params, ServeConfig(max_seq=64, slots=3))
+    ref = Engine(bundle, params,
+                 ServeConfig(max_seq=64, slots=3, prefill_chunk=2))
     r1, r2 = ref.generate(p1, 5), ref.generate(p2, 5)
     assert ref.prefix_hits == 0
     assert ref.prefill_tokens_computed == len(p1) + len(p2)
 
     eng = Engine(bundle, params,
-                 ServeConfig(max_seq=64, slots=3, prefix_reuse=True))
+                 ServeConfig(max_seq=64, slots=3, prefix_reuse=True,
+                             prefill_chunk=2))
     assert eng.supports_prefix_reuse
     o1 = eng.generate(p1, 5)
     o2 = eng.generate(p2, 5)     # hits p1's retained 12-token prefix
@@ -124,6 +127,63 @@ def test_engine_prefix_reuse_bit_identical_and_cheaper(serve_model):
     assert eng.prefix_tokens_reused == 12
     assert eng.prefill_tokens_computed == \
         ref.prefill_tokens_computed - 12
+
+
+def test_engine_prefix_reuse_copies_whole_chunks(serve_model):
+    """Reuse rounds down to whole prefill chunks: the copied rows and
+    the chunks prefilled after them are the programs a fresh prefill
+    runs, so the streams stay bit-identical on every backend."""
+    bundle, params = serve_model
+    V = bundle.cfg.vocab
+    rng = np.random.default_rng(5)
+    shared = rng.integers(0, V, 12)
+    p1 = np.concatenate([shared, rng.integers(0, V, 9)])
+    p2 = np.concatenate([shared, rng.integers(0, V, 7)])
+
+    ref = Engine(bundle, params,
+                 ServeConfig(max_seq=64, slots=2, prefill_chunk=8))
+    r1, r2 = ref.generate(p1, 4), ref.generate(p2, 4)
+    eng = Engine(bundle, params,
+                 ServeConfig(max_seq=64, slots=2, prefix_reuse=True,
+                             prefill_chunk=8))
+    assert (eng.generate(p1, 4), eng.generate(p2, 4)) == (r1, r2)
+    assert eng.prefix_hits == 1
+    assert eng.prefix_tokens_reused == 8          # 12 shared -> 1 chunk
+    assert eng.prefill_tokens_computed == len(p1) + len(p2) - 8
+    with pytest.raises(ValueError):
+        ServeConfig(prefill_chunk=0)
+
+
+def test_engine_prefix_reuse_skips_ragged_and_decoded_rows(serve_model):
+    """A second turn (turn 1's prompt + output + more tokens) copies
+    only the rows whole prefill chunks wrote: turn 1's ragged last
+    chunk and its decoded rows came from other call shapes."""
+    bundle, params = serve_model
+    V = bundle.cfg.vocab
+    rng = np.random.default_rng(6)
+    turn1 = rng.integers(0, V, 13)            # chunks of 4: 3 whole + 1
+    scfg = ServeConfig(max_seq=64, slots=2, prefix_reuse=True,
+                       prefill_chunk=4)
+    ref = Engine(bundle, params,
+                 ServeConfig(max_seq=64, slots=2, prefill_chunk=4))
+    eng = Engine(bundle, params, scfg)
+    out1 = eng.generate(turn1, 6)
+    assert out1 == ref.generate(turn1, 6)
+    assert list(eng.chunk_rows) == [12, 0]
+    turn2 = np.concatenate([out1, rng.integers(0, V, 3)])
+    assert eng.generate(turn2, 4) == ref.generate(turn2, 4)
+    # 18 cached rows match turn 2 (13 prompt + 5 decoded), 12 are whole
+    # chunks
+    assert eng.prefix_hits == 1 and eng.prefix_tokens_reused == 12
+    assert ref.prefix_hits == 0
+
+
+def test_prefill_chunk_in_effect():
+    """Chunks only where reuse needs them, or where asked for."""
+    assert ServeConfig().chunk is None
+    assert ServeConfig(prefix_reuse=True).chunk == REUSE_PREFILL_CHUNK
+    assert ServeConfig(prefill_chunk=8).chunk == 8
+    assert ServeConfig(prefix_reuse=True, prefill_chunk=8).chunk == 8
 
 
 def test_engine_prefix_reuse_concurrent_slots(serve_model):
@@ -135,11 +195,13 @@ def test_engine_prefix_reuse_concurrent_slots(serve_model):
     pa = np.concatenate([shared, rng.integers(0, V, 3)])
     pb = np.concatenate([shared, rng.integers(0, V, 5)])
 
-    ref = Engine(bundle, params, ServeConfig(max_seq=64, slots=2))
+    ref = Engine(bundle, params,
+                 ServeConfig(max_seq=64, slots=2, prefill_chunk=2))
     ra, rb = ref.generate(pa, 4), ref.generate(pb, 4)
 
     eng = Engine(bundle, params,
-                 ServeConfig(max_seq=64, slots=2, prefix_reuse=True))
+                 ServeConfig(max_seq=64, slots=2, prefix_reuse=True,
+                             prefill_chunk=2))
     sa = eng.add_request(pa)
     sb = eng.add_request(pb)      # pa still live -> 10-token hit
     assert eng.prefix_hits == 1 and eng.prefix_tokens_reused == 10
@@ -154,7 +216,8 @@ def test_engine_prefix_miss_no_reuse(serve_model):
     V = bundle.cfg.vocab
     rng = np.random.default_rng(2)
     eng = Engine(bundle, params,
-                 ServeConfig(max_seq=64, slots=2, prefix_reuse=True))
+                 ServeConfig(max_seq=64, slots=2, prefix_reuse=True,
+                             prefill_chunk=2))
     p1 = rng.integers(1, V // 2, 6)
     p2 = rng.integers(V // 2, V, 6)           # disjoint token ranges
     eng.generate(p1, 3)
@@ -170,7 +233,8 @@ def test_cluster_prefix_aware_reduces_prefill_work(serve_model):
     bundle, params = serve_model
     V = bundle.cfg.vocab
     rng = np.random.default_rng(3)
-    scfg = ServeConfig(max_seq=64, slots=2, prefix_reuse=True)
+    scfg = ServeConfig(max_seq=64, slots=2, prefix_reuse=True,
+                       prefill_chunk=2)
     # 3 prefix families over 2 replicas: round-robin necessarily
     # scatters each family across both replicas, prefix-aware pins
     # each family to the replica that already holds its prefix
@@ -202,7 +266,8 @@ def test_cluster_streams_identical_across_replica_counts(serve_model):
     bundle, params = serve_model
     V = bundle.cfg.vocab
     rng = np.random.default_rng(4)
-    scfg = ServeConfig(max_seq=64, slots=2, prefix_reuse=True)
+    scfg = ServeConfig(max_seq=64, slots=2, prefix_reuse=True,
+                       prefill_chunk=2)
     prompts = [rng.integers(0, V, 5 + i) for i in range(4)]
 
     def run(replicas, policy):

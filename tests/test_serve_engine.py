@@ -153,3 +153,36 @@ def test_straggler_monitor():
     # straggler must not poison the average
     assert abs(m.ewma - 0.10) < 0.02
     assert not m.observe(9, 0.11)
+
+
+def test_load_engine_cuts_depth_and_keeps_widths():
+    from repro.launch.serve import load_engine
+
+    eng = load_engine("deepseek-7b", reduced=True, n_layers=2, slots=2,
+                      max_seq=32)
+    ref = get_config("deepseek-7b").reduced()
+    assert eng.cfg.n_layers == 2
+    assert (eng.cfg.d_model, eng.cfg.n_heads, eng.cfg.d_ff,
+            eng.cfg.vocab) == (ref.d_model, ref.n_heads, ref.d_ff,
+                               ref.vocab)
+    assert eng.params["main"]["attn"]["wq"].shape[0] == 2
+    toks = eng.generate(np.arange(5), 3)
+    assert len(toks) == 8 and all(0 <= t < ref.vocab for t in toks)
+    with pytest.raises(ValueError):
+        load_engine("deepseek-7b", reduced=True, n_layers=0)
+
+
+@pytest.mark.parametrize("prefill_chunk", [None, 3])
+def test_prefill_logits_give_the_first_token(prefill_chunk):
+    """The last admit's last-position logits are the ones the first
+    generated token was sampled from, whether the prompt was prefilled
+    in one call or in chunks."""
+    from repro.launch.serve import load_engine
+
+    eng = load_engine("deepseek-7b", reduced=True, n_layers=2, slots=2,
+                      max_seq=32, prefill_chunk=prefill_chunk)
+    prompt = np.arange(3, 11)
+    sid = eng.add_request(prompt)
+    logits = np.asarray(eng.prefill_logits)
+    assert logits.shape == (eng.cfg.vocab,)
+    assert eng.slot_tokens[sid][-1] == int(logits.argmax())

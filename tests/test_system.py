@@ -152,8 +152,7 @@ def test_hlo_walker_counts_scan_trips():
     cost = module_costs(c.as_text())
     expect = 5 * 2 * 8 * 64 * 64 * 3        # fwd + 2 bwd matmuls per layer
     assert abs(cost.flops - expect) / expect < 0.05
-    from repro import compat
-    ca = compat.cost_analysis(c)
+    ca = c.cost_analysis()
     assert cost.flops > 2 * float(ca.get("flops", 0)), \
         "walker must exceed XLA's trip-uncounted flops"
 
