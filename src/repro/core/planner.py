@@ -117,11 +117,12 @@ class PlannerStats:
     # one-program step counters (fused execute_step + scan capture)
     fused_steps: int = 0         # steps run as ONE exchange+kernel program
     scan_captures: int = 0       # steady-state cycles captured as lax.scan
-    # executor dispatches the LAST step cost the host: 1 for a fused
-    # execute_step, 2 under the §4.2 overlap schedule (messages ∥
-    # commit, then kernel), 0 for a step executed inside a captured
-    # scan (its one-off launch is accounted in scan_captures)
-    python_dispatches_per_step: float = 1.0
+    captured_steps: int = 0      # steps run inside a captured scan
+    # host->executor program launches of the steps run outside a scan:
+    # 1 for a fused execute_step, 2 for the two-phase path or the §4.2
+    # overlap schedule (messages, then kernel; 1 without a kernel).  A
+    # captured step adds none: its scan's one launch is a scan_capture
+    dispatches: int = 0
     # fault-tolerance counters (run_pipeline recovery path)
     recoveries: int = 0          # fault -> restore -> resume cycles
     checkpoint_restores: int = 0  # per-array planned restore writes
@@ -154,8 +155,8 @@ class PlannerStats:
         self.plans_computed = self.hits_history = self.hits_state_compare = 0
         self.intersect_ops = self.gdef_updates = self.state_compares = 0
         self.candidate_pairs = self.pairs_pruned = self.commit_replays = 0
-        self.fused_steps = self.scan_captures = 0
-        self.python_dispatches_per_step = 1.0
+        self.fused_steps = self.scan_captures = self.captured_steps = 0
+        self.dispatches = 0
         self.recoveries = self.checkpoint_restores = 0
         self.elastic_shrinks = self.straggler_events = self.steps_replayed = 0
         self.rebalances = 0

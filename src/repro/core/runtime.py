@@ -50,6 +50,7 @@ from .offsets import AbsoluteSpec, AccessSpec
 from .partition import Box, Partition, PartitionTable
 from .planner import Access, ArrayCommPlan, CommKind, CommPlan, Planner
 from .sections import SectionSet
+from .spans import span
 
 # identity elements for reductions over an empty domain (max/min have
 # none — an empty max/min is a caller error, not a value)
@@ -150,7 +151,8 @@ class HDArrayRuntime:
         per_device = tuple(
             self._clip_region_to_array(part.region(p), arr) for p in range(self.nproc)
         )
-        self.executor.write(arr, data, per_device)
+        with span("hdarray.write"):
+            self.executor.write(arr, data, per_device)
         arr.record_write(per_device)
 
     def write_replicated(self, arr: HDArray, data: np.ndarray) -> None:
@@ -158,7 +160,9 @@ class HDArrayRuntime:
         until someone redefines a section).  Supersedes every pending
         send: the whole sGDEF empties (see `HDArray.record_replicated`)."""
         full = SectionSet.full(arr.shape)
-        self.executor.write(arr, data, tuple(full for _ in range(self.nproc)))
+        with span("hdarray.write"):
+            self.executor.write(arr, data,
+                                tuple(full for _ in range(self.nproc)))
         arr.record_replicated()
 
     def read(self, arr: HDArray, part_id: int) -> np.ndarray:
@@ -166,12 +170,14 @@ class HDArrayRuntime:
         per_device = tuple(
             self._clip_region_to_array(part.region(p), arr) for p in range(self.nproc)
         )
-        return self.executor.read(arr, per_device)
+        with span("hdarray.read"):
+            return self.executor.read(arr, per_device)
 
     def read_coherent(self, arr: HDArray) -> np.ndarray:
         """Assemble the globally coherent view from each device's valid
         sections (controller-side gather)."""
-        return self.executor.read(arr, tuple(arr.valid))
+        with span("hdarray.read"):
+            return self.executor.read(arr, tuple(arr.valid))
 
     # -- the core call -----------------------------------------------------
     def apply_kernel(
@@ -195,35 +201,38 @@ class HDArrayRuntime:
         overlap that is on the host thread while messages are still in
         flight — so fault injection can tear a step mid-commit."""
         part = self.parts[part_id]
-        plan = self.planner.plan(kernel_name, part, arrays, uses, defs)
+        with span("hdarray.plan"):
+            plan = self.planner.plan(kernel_name, part, arrays, uses, defs)
 
         def _commit() -> None:
             if _fault_hook is not None:
                 _fault_hook("commit")
-            self.planner.commit(plan, arrays, part)
+            with span("hdarray.commit"):
+                self.planner.commit(plan, arrays, part)
 
         stats = self.planner.stats
         if self._scheduler is not None:
-            self._scheduler.step(
-                plan, part, kernel, arrays, self.arrays, uses, defs, kw,
-                commit=_commit)
-            # messages ∥ commit, then the kernel: two host dispatches
-            stats.python_dispatches_per_step = 2.0
+            with span("hdarray.dispatch"):
+                self._scheduler.step(
+                    plan, part, kernel, arrays, self.arrays, uses, defs, kw,
+                    commit=_commit)
+            # messages ∥ commit, then the kernel
+            stats.dispatches += 2 if kernel is not None else 1
         else:
             # ONE runtime->executor call for the whole step: a fusing
             # backend traces exchange + kernel into a single device
             # program (True); the default runs the classic two-phase
             # path (False)
-            fused = self.executor.execute_step(
-                plan, self.arrays, kernel, part.regions, arrays,
-                uses=uses, defs=defs, kw=kw)
+            with span("hdarray.dispatch"):
+                fused = self.executor.execute_step(
+                    plan, self.arrays, kernel, part.regions, arrays,
+                    uses=uses, defs=defs, kw=kw)
             _commit()
             if fused:
                 stats.fused_steps += 1
-                stats.python_dispatches_per_step = 1.0
+                stats.dispatches += 1
             else:
-                stats.python_dispatches_per_step = \
-                    2.0 if kernel is not None else 1.0
+                stats.dispatches += 2 if kernel is not None else 1
         self.log_plan(kernel_name, plan)
         return plan
 
@@ -269,20 +278,21 @@ class HDArrayRuntime:
         work partitions are rewritten, and a ``"rebalance"`` record
         lands in ``recovery_log``.  Scan capture is gated on the mesh
         looking balanced, so captures re-arm on the new layout."""
-        if recovery is not None:
-            return self._run_pipeline_recoverable(list(steps), recovery,
-                                                  rebalance)
-        if self._scheduler is None:
-            # rebalancing rewrites the remaining steps' part ids: work
-            # on copies so the caller's dicts survive
+        with span("hdarray.run_pipeline"):
+            if recovery is not None:
+                return self._run_pipeline_recoverable(list(steps), recovery,
+                                                      rebalance)
+            if self._scheduler is None:
+                # rebalancing rewrites the remaining steps' part ids:
+                # work on copies so the caller's dicts survive
+                if rebalance is not None:
+                    steps = [dict(st) for st in steps]
+                return self._run_pipeline_serial(list(steps), rebalance)
             if rebalance is not None:
-                steps = [dict(st) for st in steps]
-            return self._run_pipeline_serial(list(steps), rebalance)
-        if rebalance is not None:
-            raise ValueError(
-                "rebalance requires the serial or recovery pipeline "
-                "path (overlap=False, or a RecoveryPolicy)")
-        return self._scheduler.pipeline(self, list(steps))
+                raise ValueError(
+                    "rebalance requires the serial or recovery pipeline "
+                    "path (overlap=False, or a RecoveryPolicy)")
+            return self._scheduler.pipeline(self, list(steps))
 
     # -- steady-state capture (one dispatch for K steps) -----------------
     #: longest cycle period the serial pipeline looks for
@@ -318,17 +328,22 @@ class HDArrayRuntime:
                             defs=steps[i - d + j]["defs"],
                             kw=steps[i - d + j].get("kw", {}),
                         ) for j in range(d)]
-                        runner = self.executor.capture_cycle(cycle, reps)
+                        with span("hdarray.capture"):
+                            runner = self.executor.capture_cycle(cycle,
+                                                                 reps)
+                            if runner is not None:
+                                runner()      # reps*d steps, ONE dispatch
                         if runner is None:
                             try_capture = False
                         else:
-                            runner()          # reps*d steps, ONE dispatch
                             stats.scan_captures += 1
-                            for k in range(reps * d):
-                                plans[i + k] = self._replay_step_metadata(
-                                    steps[i + k])
-                                steady[i + k] = True
-                            stats.python_dispatches_per_step = 0.0
+                            stats.captured_steps += reps * d
+                            with span("hdarray.replay"):
+                                for k in range(reps * d):
+                                    plans[i + k] = \
+                                        self._replay_step_metadata(
+                                            steps[i + k])
+                                    steady[i + k] = True
                             i += reps * d
                             continue
             before = stats.commit_replays

@@ -12,8 +12,13 @@ cache: they materialize only on ``read`` / ``write`` / non-traceable
 kernels / the reduce local fold (the oracle-parity paths), so a
 steady-state step does zero ``np.stack`` / ``device_put`` /
 ``device_get``.  ``h2d_transfers`` / ``d2h_transfers`` count the
-full-buffer crossings — benchmarks and tests assert they stay flat
-while a pipeline runs.
+full-buffer crossings (``h2d_bytes`` / ``d2h_bytes`` their bytes) —
+benchmarks and tests assert they stay flat while a pipeline runs.
+``programs_built`` counts the misses of the program caches: a warm
+call builds none.  Every jitted program is named after its role
+(``jit_hdarray_step``, ``jit_hdarray_scan``, ``jit_hdarray_plan``,
+``jit_hdarray_kernel``, ``jit_hdarray_reduce``), the name a profiler
+trace shows for it.
 
 **Plan fusion.**  ``execute_plan`` traces ALL arrays' messages of a
 CommPlan into ONE jitted ``shard_map`` program (cached by a plan-level
@@ -73,10 +78,10 @@ steady-state cycle (every step's plan a §4.2 cache hit and its commit
 a fingerprint replay for two full periods) into ONE jitted
 ``lax.scan`` over ``reps`` repetitions with donated carries: K more
 steps of the pipeline become one dispatch, and the per-step host
-dispatch count (``PlannerStats.python_dispatches_per_step``) drops to
-zero.  The scan body chains the same step tracers the fused step
-programs use, so the result stays bit-identical to the unfused
-oracle.
+dispatch count drops to zero (``PlannerStats.captured_steps`` add no
+``PlannerStats.dispatches``).  The scan body chains the same step
+tracers the fused step programs use, so the result stays
+bit-identical to the unfused oracle.
 
 ``HDArrayReduce`` keeps the oracle split: the local fold runs on the
 host mirrors (one d2h sync when the device copy is newer) and the
@@ -193,7 +198,12 @@ class JaxExecutor(SimExecutor):
         # other scalar traffic are not full buffers and do not count)
         self.h2d_transfers: int = 0
         self.d2h_transfers: int = 0
+        self.h2d_bytes: int = 0
+        self.d2h_bytes: int = 0
         self.device_kernel_launches: int = 0
+        # misses of the program caches below: each is a trace + compile
+        # (a warm call builds none)
+        self.programs_built: int = 0
         self._mesh = None
         self._sharding = None
         # structure signature -> (jitted program, counts delta)
@@ -266,6 +276,7 @@ class JaxExecutor(SimExecutor):
         self.buffers[name] = list(stacked)   # per-rank writable views
         self._host_ok[name] = True
         self.d2h_transfers += 1
+        self.d2h_bytes += stacked.nbytes
 
     def _to_device(self, arr: "HDArray") -> None:
         name = arr.name
@@ -278,6 +289,7 @@ class JaxExecutor(SimExecutor):
         self._device[name] = jax.device_put(stacked, self._sharding)
         self._device_ok[name] = True
         self.h2d_transfers += 1
+        self.h2d_bytes += stacked.nbytes
 
     @staticmethod
     def _donate(n: int) -> tuple:
@@ -401,6 +413,7 @@ class JaxExecutor(SimExecutor):
             if prog is None:
                 prog = self._build_plan_program(groups)
                 self._programs[sig] = prog
+                self.programs_built += 1
             stages, counts = prog
             devs = [self._device[arr.name] for arr, _m, _k in groups]
             for gi, fn in stages:
@@ -433,14 +446,17 @@ class JaxExecutor(SimExecutor):
             if prog is None:
                 prog = self._build_plan_program([(arr, msgs, kind)])
                 self._programs[sig] = prog
+                self.programs_built += 1
             stages, counts = prog
             stacked = np.stack(self.buffers[arr.name])
             self.h2d_transfers += 1
+            self.h2d_bytes += stacked.nbytes
             val = jax.device_put(stacked, self._sharding)
             for _gi, fn in stages:           # single array: gi is 0/None
                 val = fn(val) if _gi is not None else fn(val)[0]
             out = np.asarray(jax.device_get(val))
             self.d2h_transfers += 1
+            self.d2h_bytes += out.nbytes
             bufs = self.buffers[arr.name]
             # write back ONLY the received sections: everything else is
             # untouched by the program, and the overlap scheduler may be
@@ -486,17 +502,18 @@ class JaxExecutor(SimExecutor):
             stages = []
             for gi, steps in enumerate(per_group):
                 for collect, apply in steps:
-                    def body1(xb, _c=collect, _a=apply):
+                    def hdarray_plan_stage(xb, _c=collect, _a=apply):
                         idx = jax.lax.axis_index(axis)
                         x = xb[0]
                         return _a(x, _c(x, idx), idx)[None]
                     stages.append((gi, jax.jit(jax.shard_map(
-                        body1, mesh=self._mesh, in_specs=P(axis),
+                        hdarray_plan_stage, mesh=self._mesh,
+                        in_specs=P(axis),
                         out_specs=P(axis), check_vma=False),
                         donate_argnums=(0,))))
             return stages, counts
 
-        def body(*xbs):
+        def hdarray_plan(*xbs):
             # xbs: each array's (1, *shape) block of its stacked buffer
             idx = jax.lax.axis_index(axis)
             xs = [xb[0] for xb in xbs]
@@ -513,7 +530,7 @@ class JaxExecutor(SimExecutor):
 
         k = len(groups)
         fn = jax.jit(jax.shard_map(
-            body, mesh=self._mesh,
+            hdarray_plan, mesh=self._mesh,
             in_specs=tuple(P(axis) for _ in range(k)),
             out_specs=tuple(P(axis) for _ in range(k)),
             check_vma=False), donate_argnums=self._donate(k))
@@ -765,6 +782,7 @@ class JaxExecutor(SimExecutor):
                                                    arrays, kw))
                 if kw_key is not None:
                     self._programs[key] = prog
+                self.programs_built += 1
             if pershard:
                 _tag, rank_fns, out_names = prog
                 if not out_names:
@@ -820,7 +838,7 @@ class JaxExecutor(SimExecutor):
 
         branches = [make_branch(r) for r in regions]
 
-        def body(*xbs):
+        def hdarray_kernel(*xbs):
             idx = jax.lax.axis_index(axis)
             out = jax.lax.switch(idx, branches,
                                  tuple(xb[0] for xb in xbs))
@@ -828,7 +846,7 @@ class JaxExecutor(SimExecutor):
 
         donate = tuple(i for i, n in enumerate(names) if n in defined)
         fn = jax.jit(jax.shard_map(
-            body, mesh=self._mesh,
+            hdarray_kernel, mesh=self._mesh,
             in_specs=tuple(P(axis) for _ in names),
             out_specs=tuple(P(axis) for _ in out_names),
             check_vma=False), donate_argnums=donate)
@@ -859,12 +877,12 @@ class JaxExecutor(SimExecutor):
         donate = tuple(i for i, n in enumerate(names) if n in defined)
 
         def make_fn(region):
-            def body(*ops):
+            def hdarray_kernel_shard(*ops):
                 # ops are (1, *shape) shard views; kernel sees slabs
                 bufs = {n: o[0] for n, o in zip(names, ops)}
                 res = kernel(region, bufs, **kw) or {}
                 return tuple(res.get(n, bufs[n])[None] for n in out_names)
-            return jax.jit(body, donate_argnums=donate)
+            return jax.jit(hdarray_kernel_shard, donate_argnums=donate)
 
         rank_fns = [None if r.is_empty() else make_fn(r) for r in regions]
         return ("pershard", rank_fns, out_names)
@@ -999,6 +1017,7 @@ class JaxExecutor(SimExecutor):
                                                 part_regions, arrays, kw,
                                                 split)
                 self._programs[key] = prog
+                self.programs_built += 1
             self._dispatch_step(prog, groups, arrays)
         return True
 
@@ -1137,12 +1156,13 @@ class JaxExecutor(SimExecutor):
             stages = []
             for gi, steps in zip(gidx, per_group):
                 for collect, apply in steps:
-                    def body1(xb, _c=collect, _a=apply):
+                    def hdarray_step_stage(xb, _c=collect, _a=apply):
                         idx = jax.lax.axis_index(axis)
                         x = xb[0]
                         return _a(x, _c(x, idx), idx)[None]
                     stages.append((gi, jax.jit(jax.shard_map(
-                        body1, mesh=self._mesh, in_specs=P(axis),
+                        hdarray_step_stage, mesh=self._mesh,
+                        in_specs=P(axis),
                         out_specs=P(axis), check_vma=False),
                         donate_argnums=(0,))))
             kprog = None
@@ -1156,14 +1176,14 @@ class JaxExecutor(SimExecutor):
                                      kernel, kw, out_kernel, regions,
                                      split)
 
-        def body(*xbs):
+        def hdarray_step(*xbs):
             idx = jax.lax.axis_index(axis)
             xs = step_fn([xb[0] for xb in xbs], idx)
             return tuple(xs[names.index(n)][None] for n in out_names)
 
         donate = tuple(i for i, n in enumerate(names) if n in out_names)
         fn = jax.jit(jax.shard_map(
-            body, mesh=self._mesh,
+            hdarray_step, mesh=self._mesh,
             in_specs=tuple(P(axis) for _ in names),
             out_specs=tuple(P(axis) for _ in out_names),
             check_vma=False), donate_argnums=donate)
@@ -1239,6 +1259,7 @@ class JaxExecutor(SimExecutor):
             if prog is None:
                 prog = self._build_cycle_program(step_meta, union, reps)
                 self._programs[key] = prog
+                self.programs_built += 1
             fn, counts, launches, bytes_c, msgs_c = prog
 
         def run() -> None:
@@ -1292,7 +1313,7 @@ class JaxExecutor(SimExecutor):
                 names, lowered_idx, kernel, kw, out_kernel, regions,
                 split))
 
-        def body(*xbs):
+        def hdarray_scan(*xbs):
             idx = jax.lax.axis_index(axis)
 
             def one(carry, _):
@@ -1306,7 +1327,7 @@ class JaxExecutor(SimExecutor):
             return tuple(o[None] for o in out)
 
         fn = jax.jit(jax.shard_map(
-            body, mesh=self._mesh,
+            hdarray_scan, mesh=self._mesh,
             in_specs=tuple(P(axis) for _ in names),
             out_specs=tuple(P(axis) for _ in names),
             check_vma=False), donate_argnums=self._donate(len(names)))
@@ -1357,6 +1378,7 @@ class JaxExecutor(SimExecutor):
             if prog is None:
                 prog = self._build_reduce_program(op)
                 self._programs[key] = prog
+                self.programs_built += 1
             fn, counts = prog
             out = np.asarray(jax.device_get(
                 fn(jax.device_put(vals, self._sharding))))
@@ -1379,7 +1401,7 @@ class JaxExecutor(SimExecutor):
         prims = {"sum": jax.lax.psum, "max": jax.lax.pmax,
                  "min": jax.lax.pmin}
 
-        def body(xb):
+        def hdarray_reduce(xb):
             v = xb[0]
             if op == "prod":
                 # no lax.pprod primitive: all_gather + local fold is the
@@ -1390,6 +1412,6 @@ class JaxExecutor(SimExecutor):
             return r[None]
 
         fn = jax.jit(jax.shard_map(
-            body, mesh=self._mesh, in_specs=P(axis), out_specs=P(axis),
-            check_vma=False))
+            hdarray_reduce, mesh=self._mesh, in_specs=P(axis),
+            out_specs=P(axis), check_vma=False))
         return fn, {REDUCE_COLLECTIVES[op]: 1}

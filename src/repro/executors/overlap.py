@@ -211,10 +211,12 @@ class OverlapScheduler:
                 self.steps_overlapped += 1
             finally:
                 comm.result()
+            runtime.planner.stats.dispatches += 1
             if st.get("kernel") is not None:
                 self.executor.run_kernel(st["kernel"], part.regions, arrays,
                                          defs=tuple(st["defs"]),
                                          **st.get("kw", {}))
+                runtime.planner.stats.dispatches += 1
             runtime.log_plan(st["kernel_name"], plan)
             plans.append(plan)
             plan = next_plan

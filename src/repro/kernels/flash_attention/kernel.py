@@ -131,5 +131,6 @@ def flash_attention_pallas(q, k, v, *, qpos, window: Optional[int] = None,
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
+        name="flash_attention",
     )(qposp, qp, kp, vp)
     return jnp.swapaxes(out[:, :, :T], 1, 2)

@@ -64,5 +64,6 @@ def gemm_pallas(a, b, *, alpha: float = 1.0, block_m: int = 256,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="gemm",
     )(a, b)
     return out[:M, :N]

@@ -4,12 +4,13 @@ The ambition chain, counter-verified at each link:
 
 * a serial apply_kernel step on the resident jax backend runs exchange
   AND device kernel as ONE jitted program (``PlannerStats.fused_steps``,
-  ``python_dispatches_per_step == 1``);
+  one ``PlannerStats.dispatches`` per step);
 * a steady-state pipeline (every step a §4.2 plan hit + commit replay
   for two periods) is captured as ONE jitted ``lax.scan``
   (``scan_captures``), after which the per-step host dispatch count is
-  ZERO — and the results stay bit-identical to the unfused Sim oracle,
-  with an identical ``comm_log``;
+  ZERO (``captured_steps`` add no ``dispatches``) — and the results
+  stay bit-identical to the unfused Sim oracle, with an identical
+  ``comm_log``;
 * the real Pallas kernels (interpret mode on CPU) ride inside those
   fused programs via the :mod:`repro.kernels.hd` factories.
 """
@@ -73,23 +74,38 @@ def _jacobi_pipeline(rt, n=48, steps=20, kernels=(_jac_ab, _jac_ba)):
 def test_fused_steps_counter_and_dispatch_gauge():
     _need_devices(4)
     rt = HDArrayRuntime(4, backend="jax")
-    _jacobi_pipeline(rt, steps=4)
     st = rt.planner.stats
+    d0 = st.dispatches
+    _jacobi_pipeline(rt, steps=4)
     # every serial device-kernel step fused exchange+kernel into ONE
     # program (4 steps run before any capture window can open)
     assert st.fused_steps == 4
-    assert st.scan_captures == 0
-    assert st.python_dispatches_per_step == 1.0
+    assert st.scan_captures == 0 and st.captured_steps == 0
+    # one host dispatch per step
+    assert (st.dispatches - d0) / 4 == 1.0
     rt.close()
 
 
 def test_sim_pipeline_never_captures():
     rt = HDArrayRuntime(4, backend="sim")
-    _jacobi_pipeline(rt, steps=12)
     st = rt.planner.stats
+    d0 = st.dispatches
+    _jacobi_pipeline(rt, steps=12)
     assert st.fused_steps == 0 and st.scan_captures == 0
+    assert st.captured_steps == 0
     # unfused step with a kernel: exchange dispatch + kernel dispatch
-    assert st.python_dispatches_per_step == 2.0
+    assert (st.dispatches - d0) / 12 == 2.0
+    rt.close()
+
+
+def test_overlap_pipeline_two_dispatches_per_step():
+    rt = HDArrayRuntime(4, backend="sim", overlap=True)
+    st = rt.planner.stats
+    d0 = st.dispatches
+    _jacobi_pipeline(rt, steps=6)
+    # messages on the comm thread, then the kernel: two per step
+    assert (st.dispatches - d0) / 6 == 2.0
+    assert st.captured_steps == 0
     rt.close()
 
 
@@ -101,15 +117,18 @@ def test_steady_pipeline_captured_as_scan_zero_dispatches():
 
     rt = HDArrayRuntime(8, backend="jax")
     ex = rt.executor
-    a_jax, b_jax, log_jax = _jacobi_pipeline(rt, steps=20)
     st = rt.planner.stats
+    d0 = st.dispatches
+    a_jax, b_jax, log_jax = _jacobi_pipeline(rt, steps=20)
 
     # the steady state was detected and captured as >= 1 lax.scan ...
     assert st.scan_captures >= 1
-    # ... covering every step after the two-period witness window
+    # ... covering every step after the witness window, the last
+    # included: the first 10 steps run fused, the other 10 in the scan
     assert st.fused_steps + st.scan_captures < 20
-    # the LAST steps ran inside the scan: zero per-step host dispatches
-    assert st.python_dispatches_per_step == 0.0
+    assert st.fused_steps == 10 and st.captured_steps == 10
+    # one host dispatch per fused step, none for the captured ones
+    assert st.dispatches - d0 == st.fused_steps
     # scan program cached under a ("scan", ...) signature
     assert any(k and k[0] == "scan" for k in ex._programs)
     # residency held: 2 writes up, 0 down until the reads
@@ -198,11 +217,15 @@ def test_hd_gemm_pallas_kernel_fused_and_captured():
     rt_sim.close()
 
     rt = HDArrayRuntime(8, backend="jax")
-    c_jax = _gemm_program(rt, kern)
     st = rt.planner.stats
-    # period-1 steady state: captured after the two-step witness
+    d0 = st.dispatches
+    c_jax = _gemm_program(rt, kern)
+    # period-1 steady state: captured after the witness, up to the last
+    # of the 8 steps: 4 fused, then 4 inside the scan
     assert st.scan_captures >= 1
-    assert st.python_dispatches_per_step == 0.0
+    assert st.fused_steps == 4 and st.captured_steps == 4
+    # one host dispatch per fused step, none for the captured ones
+    assert st.dispatches - d0 == st.fused_steps
     # one kernel source, bit-identical across backends (both run the
     # same jitted interpret-mode Pallas program on this host)
     assert np.array_equal(c_sim, c_jax)
