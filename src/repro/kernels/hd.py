@@ -7,7 +7,9 @@ style per-device kernels — ``kernel(region, bufs) -> {name: buffer}``
 factories here bridge the two: each returns a device kernel that slices
 its work region out of the full per-device buffers, runs the REAL op
 (Pallas on TPU, interpret-mode Pallas or the jnp oracle elsewhere —
-pick with ``impl=``), and writes the result back functionally.
+pick with ``impl=``), and writes the result back functionally.  The
+Jacobi bridge's Pallas stencil writes an interior region straight into
+its destination buffer instead (see :func:`make_jacobi_kernel`).
 
 Because the result is a ``device_kernel``, the resident jax backend
 traces it into its fused one-program steps (exchange + compute in a
@@ -23,9 +25,21 @@ key on the executor.
 """
 from __future__ import annotations
 
+from dataclasses import dataclass
 from typing import Optional
 
 from repro.executors.kernels import device_kernel, kernel_put
+
+
+@dataclass
+class JacobiPaths:
+    """Cumulative count of the path a Jacobi bridge kernel took — once
+    per trace on the jax backend, once per call on sim; read as deltas
+    like ``PlannerStats``.  ``in_place``: the Pallas stencil wrote the
+    region into the destination buffer; ``kernel_put``: the op's array
+    was sliced and written back by ``kernel_put``."""
+    in_place: int = 0
+    kernel_put: int = 0
 
 
 def make_gemm_kernel(a: str = "A", b: str = "B", c: str = "C", *,
@@ -52,16 +66,32 @@ def make_jacobi_kernel(src: str = "A", dst: str = "B", *,
     """One Jacobi sweep ``dst[region] = avg4(src)`` over an INTERIOR
     work region (the standard idiom: work partition over
     ``Box.make((1, M-1), (1, N-1))``, boundary rows/cols pass through).
-    The op runs on the region's row band plus its one-row halo — the
-    halo rows themselves arrive via the planner's ghost-cell
-    exchange."""
-    from repro.kernels.stencil_hd.ops import jacobi_step
+    The halo rows arrive via the planner's ghost-cell exchange.
+
+    With the Pallas stencil and distinct ``src``/``dst`` the op reads
+    ``src`` in place and writes the region straight into ``dst``'s
+    buffer (``jacobi_step(..., into=)``): no slab, no slice, no
+    write-back.  Otherwise (``impl="ref"``, or ``src == dst``, which an
+    in-place sweep would read while writing) the op runs on the
+    region's row band plus its one-row halo and the result is written
+    back by ``kernel_put``.  The kernel's ``paths``
+    (:class:`JacobiPaths`) counts which path each call took."""
+    from repro.kernels.stencil_hd.ops import jacobi_step, resolve_impl
+
+    paths = JacobiPaths()
 
     @device_kernel
     def jacobi_hd_kernel(region, bufs):
         (r0, r1), (c0, c1) = region.bounds
         x = bufs[src]
-        n = x.shape[1]
+        m, n = x.shape
+        if (src != dst and resolve_impl(impl) == "pallas"
+                and 1 <= r0 and r1 <= m - 1 and 1 <= c0 and c1 <= n - 1):
+            paths.in_place += 1
+            return {dst: jacobi_step(x, into=bufs[dst], rows=(r0, r1),
+                                     cols=(c0, c1), impl=impl,
+                                     interpret=interpret)}
+        paths.kernel_put += 1
         assert r0 >= 1 and c0 >= 1 and c1 <= n - 1, (
             "jacobi kernel needs an interior work region")
         # slab = band + vertical halo; the op's edge pass-through rows/
@@ -73,6 +103,7 @@ def make_jacobi_kernel(src: str = "A", dst: str = "B", *,
                                 (slice(r0, r1), slice(c0, c1)),
                                 sw[1:-1, c0:c1])}
 
+    jacobi_hd_kernel.paths = paths
     return jacobi_hd_kernel
 
 

@@ -82,6 +82,27 @@ def test_jacobi_pallas_compiles(one_chip, shape):
     assert _has_kernel(compiled)
 
 
+# the sweep written into a donated destination, over the regions the
+# HDArray Jacobi bridge hands it at 16384^2: the whole interior on one
+# chip, a rank's band interior on four, and a one-row boundary box.
+# The destination's buffer is the output's: aliased, never copied.
+@pytest.mark.parametrize("rows", [(1, 16383), (4097, 8191), (4095, 4096)])
+def test_jacobi_in_place_compiles_aliased(one_chip, rows):
+    import re
+
+    from repro.kernels.stencil_hd.kernel import jacobi_pallas_into
+
+    n = 16384
+    x = jax.ShapeDtypeStruct((n, n), jnp.float32, sharding=one_chip)
+    fn = jax.jit(lambda a, d: jacobi_pallas_into(a, d, rows=rows,
+                                                 cols=(1, n - 1)),
+                 donate_argnums=1)
+    compiled = fn.lower(x, x).compile()
+    assert _has_kernel(compiled)
+    assert compiled.memory_analysis().alias_size_in_bytes == 4 * n * n
+    assert not re.search(r"\bcopy\(", compiled.as_text())
+
+
 @pytest.mark.parametrize("kv_heads,window", [(32, None), (8, 1024)])
 def test_flash_pallas_compiles(one_chip, kv_heads, window):
     from functools import partial
