@@ -37,9 +37,18 @@ class JacobiPaths:
     per trace on the jax backend, once per call on sim; read as deltas
     like ``PlannerStats``.  ``in_place``: the Pallas stencil wrote the
     region into the destination buffer; ``kernel_put``: the op's array
-    was sliced and written back by ``kernel_put``."""
+    was sliced and written back by ``kernel_put``.  ``halo_bytes`` and
+    ``keep_bytes``: what the Pallas stencil's grid fetched for its row
+    and lane halos and read from the destination, beyond its source
+    blocks (``stencil_hd.kernel.SweepPlan.fetches``)."""
     in_place: int = 0
     kernel_put: int = 0
+    halo_bytes: int = 0
+    keep_bytes: int = 0
+
+    def fetched(self, halo: int, keep: int) -> None:
+        self.halo_bytes += halo
+        self.keep_bytes += keep
 
 
 def make_gemm_kernel(a: str = "A", b: str = "B", c: str = "C", *,
@@ -75,7 +84,9 @@ def make_jacobi_kernel(src: str = "A", dst: str = "B", *,
     in-place sweep would read while writing) the op runs on the
     region's row band plus its one-row halo and the result is written
     back by ``kernel_put``.  The kernel's ``paths``
-    (:class:`JacobiPaths`) counts which path each call took."""
+    (:class:`JacobiPaths`) counts which path each call took, and the
+    bytes the Pallas stencil fetched beyond its source blocks."""
+    from repro.kernels.stencil_hd.kernel import sweep_plan
     from repro.kernels.stencil_hd.ops import jacobi_step, resolve_impl
 
     paths = JacobiPaths()
@@ -85,9 +96,12 @@ def make_jacobi_kernel(src: str = "A", dst: str = "B", *,
         (r0, r1), (c0, c1) = region.bounds
         x = bufs[src]
         m, n = x.shape
-        if (src != dst and resolve_impl(impl) == "pallas"
+        pallas = resolve_impl(impl) == "pallas"
+        if (src != dst and pallas
                 and 1 <= r0 and r1 <= m - 1 and 1 <= c0 and c1 <= n - 1):
             paths.in_place += 1
+            paths.fetched(*sweep_plan(x.shape, x.dtype, rows=(r0, r1),
+                                      cols=(c0, c1)).fetches())
             return {dst: jacobi_step(x, into=bufs[dst], rows=(r0, r1),
                                      cols=(c0, c1), impl=impl,
                                      interpret=interpret)}
@@ -97,8 +111,10 @@ def make_jacobi_kernel(src: str = "A", dst: str = "B", *,
         # slab = band + vertical halo; the op's edge pass-through rows/
         # cols are exactly the slab rows 0 and -1 (sliced off) and the
         # global cols 0 and n-1 (outside [c0, c1))
-        sw = jacobi_step(x[r0 - 1:r1 + 1, :], impl=impl,
-                         interpret=interpret)
+        slab = x[r0 - 1:r1 + 1, :]
+        if pallas:
+            paths.fetched(*sweep_plan(slab.shape, slab.dtype).fetches())
+        sw = jacobi_step(slab, impl=impl, interpret=interpret)
         return {dst: kernel_put(bufs[dst],
                                 (slice(r0, r1), slice(c0, c1)),
                                 sw[1:-1, c0:c1])}

@@ -3,8 +3,12 @@
 * ``jacobi_pallas_into`` gives the same bits as the array -> array
   stencil cut out of its row slab and written back by ``kernel_put``,
   over every kind of region the HDArray bridge is handed, in float32
-  and bfloat16, with a destination that differs from the source
-  everywhere outside the region;
+  and bfloat16, under one-lane-tile and wider blocks, with a
+  destination that differs from the source everywhere outside the
+  region;
+* what the stencil fetches beyond its source blocks at 16384^2 (the
+  bridge's ``halo_bytes`` and ``keep_bytes``) stays a small share of
+  the 8 bytes an element that its roofline counts;
 * the bridge counts which path each call took: the benchmark's
   ping-pong pipeline takes the in-place path on every trace, an
   ``impl="ref"`` kernel (or one whose source is its destination) the
@@ -23,30 +27,39 @@ from repro.core import Box
 from repro.core.partition import Partition
 from repro.executors.kernels import kernel_put
 from repro.kernels import hd
-from repro.kernels.stencil_hd.kernel import jacobi_pallas, jacobi_pallas_into
+from repro.kernels.stencil_hd.kernel import (jacobi_pallas, jacobi_pallas_into,
+                                             sweep_plan)
+from repro.kernels.stencil_hd.ref import jacobi_ref
 
 ROOT = pathlib.Path(__file__).resolve().parents[1]
 
-# ragged in both axes under (16, 128) blocks: 100 = 6 * 16 + 4 rows,
-# 300 = 2 * 128 + 44 columns
-M, N = 100, 300
-BLOCKS = dict(block_m=16, block_n=128)
+# "ragged" is ragged in both axes under (16, 128) blocks: 100 = 6 * 16
+# + 4 rows, 300 = 2 * 128 + 44 columns, so the stencil reads the
+# destination's whole blocks; "tiled" is whole (sublane, lane) tiles, so
+# it copies strips of the destination.  (16, 256) blocks span two lane
+# tiles, so the left and right strips are narrower than a block.
+SHAPES = {"ragged": (100, 300), "tiled": (96, 384)}
+M, N = SHAPES["ragged"]
+BLOCKS = [dict(block_m=16, block_n=128), dict(block_m=16, block_n=256)]
 SENTINEL = -7.0
 
 
-def _bands():
+def _bands(M, N):
     part = Partition.row(0, (M, N), 4, region=Box.make((1, M - 1),
                                                        (1, N - 1)))
     return [part.region(p).bounds for p in range(4)]
 
 
-def _regions():
+def _regions(M, N):
     cols = (1, N - 1)
     regions = {"interior": ((1, M - 1), cols),
                "unaligned_start": ((5, M - 1), cols),
-               "ragged_last_block": ((97, M - 1), cols),
+               "ragged_last_block": ((M - 3, M - 1), cols),
+               # the rows outside it in its first block are wider than
+               # a halo tile
+               "wide_outside_top": ((27, M - 1), cols),
                "inner_columns": ((20, 40), (130, 250))}
-    for p, (rows, cols) in enumerate(_bands()):
+    for p, (rows, cols) in enumerate(_bands(M, N)):
         regions[f"band{p}"] = (rows, cols)
         # the one-row boundary boxes halo_split cuts off a rank's band
         regions[f"band{p}_top_row"] = ((rows[0], rows[0] + 1), cols)
@@ -54,19 +67,30 @@ def _regions():
     return regions
 
 
-REGIONS = _regions()
+REGIONS = _regions(M, N)
 
 
+@pytest.mark.parametrize("shape", sorted(SHAPES))
+@pytest.mark.parametrize("blocks", BLOCKS,
+                         ids=lambda b: f"{b['block_m']}x{b['block_n']}")
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 @pytest.mark.parametrize("name", sorted(REGIONS))
-def test_in_place_matches_slice_and_kernel_put(name, dtype):
-    (r0, r1), (c0, c1) = rows, cols = REGIONS[name]
+def test_in_place_matches_slice_and_kernel_put(name, dtype, blocks, shape):
+    M, N = SHAPES[shape]
+    (r0, r1), (c0, c1) = rows, cols = _regions(M, N)[name]
     rng = np.random.default_rng(5)
     x = jnp.asarray(rng.standard_normal((M, N)), dtype)
     dst = jnp.full((M, N), SENTINEL, dtype)
+    plan = sweep_plan((M, N), dtype, rows=rows, cols=cols, **blocks)
+    assert plan.keep == {"tiled": "strips", "ragged": "blocks"}[shape]
     got = jacobi_pallas_into(x, dst, rows=rows, cols=cols, interpret=True,
-                             **BLOCKS)
-    sw = jacobi_pallas(x[r0 - 1:r1 + 1, :], interpret=True, **BLOCKS)
+                             **blocks)
+    slab = x[r0 - 1:r1 + 1, :]
+    sw = jacobi_pallas(slab, interpret=True, **blocks)
+    # the stencil sums in float32 whatever the storage type
+    ref = jacobi_ref(slab.astype(jnp.float32)).astype(dtype)
+    np.testing.assert_array_equal(np.asarray(sw, np.float32),
+                                  np.asarray(ref, np.float32))
     want = kernel_put(dst, (slice(r0, r1), slice(c0, c1)),
                       sw[1:-1, c0:c1])
     np.testing.assert_array_equal(np.asarray(got, np.float32),
@@ -149,8 +173,45 @@ def test_bridge_falls_back_to_kernel_put(impl, src, dst):
     bufs = {"A": jnp.asarray(rng.random((32, 256), dtype=np.float32)),
             "B": jnp.zeros((32, 256), jnp.float32)}
     out = kernel(Box.make((1, 31), (1, 255)), bufs)
-    assert kernel.paths == hd.JacobiPaths(in_place=0, kernel_put=1)
+    # the Pallas stencil over the (32, 256) slab fetches its halo tiles
+    # once; nothing is read from a destination
+    halo = 2 * (8 * 256 + 32 * 128) * 4 if impl == "pallas" else 0
+    assert kernel.paths == hd.JacobiPaths(in_place=0, kernel_put=1,
+                                          halo_bytes=halo, keep_bytes=0)
     assert set(out) == {dst}
+
+
+def _rank_boxes(n, p):
+    """Per rank, the boxes the fused steps sweep at ``n``^2 over ``p``
+    ranks by rows: its band's interior and the one-row boxes next to a
+    neighbour's halo rows."""
+    part = Partition.row(0, (n, n), p, region=Box.make((1, n - 1),
+                                                       (1, n - 1)))
+    for r in range(p):
+        (r0, r1), cols = part.region(r).bounds
+        top, bottom = r0 + (r > 0), r1 - (r < p - 1)
+        yield (r1 - r0) * (cols[1] - cols[0]), (
+            [((top, bottom), cols)] + [((r0, top), cols)] * (top > r0)
+            + [((bottom, r1), cols)] * (r1 > bottom))
+
+
+@pytest.mark.parametrize("p", [1, 4])
+def test_stencil_fetches_little_beyond_its_counted_bytes(p):
+    """At the benchmark's 16384^2 float32 the halos cost at most 7% of
+    the 8 bytes an element the roofline counts (the parent's (256,
+    1024) blocks: 15.6%) and the destination's strips at most 1.5%
+    (its whole edge blocks: 7.6% on one rank, 11.7% on four)."""
+    n = 16384
+    sds = jax.ShapeDtypeStruct((n, n), jnp.float32)
+    for elements, boxes in _rank_boxes(n, p):
+        kernel = hd.make_jacobi_kernel("A", "B", impl="pallas")
+        for rows, cols in boxes:
+            jax.eval_shape(lambda a, b: kernel(Box.make(rows, cols),
+                                               {"A": a, "B": b}), sds, sds)
+        paths, counted = kernel.paths, 8 * elements
+        assert paths.in_place == len(boxes) and paths.kernel_put == 0
+        assert 0 < paths.halo_bytes <= 0.07 * counted
+        assert 0 < paths.keep_bytes <= 0.015 * counted
 
 
 def _pallas_aliases(jaxpr):

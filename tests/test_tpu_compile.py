@@ -84,23 +84,48 @@ def test_jacobi_pallas_compiles(one_chip, shape):
 
 # the sweep written into a donated destination, over the regions the
 # HDArray Jacobi bridge hands it at 16384^2: the whole interior on one
-# chip, a rank's band interior on four, and a one-row boundary box.
-# The destination's buffer is the output's: aliased, never copied.
-@pytest.mark.parametrize("rows", [(1, 16383), (4097, 8191), (4095, 4096)])
-def test_jacobi_in_place_compiles_aliased(one_chip, rows):
+# chip, a rank's band interior on four, and a one-row boundary box
+# (strips of the destination copied by hand); and a grid of no whole
+# (sublane, lane) tiles (the destination's blocks, pipelined).  The
+# destination's buffer is the output's: aliased, never copied.
+@pytest.mark.parametrize("shape,rows", [((16384, 16384), (1, 16383)),
+                                        ((16384, 16384), (4097, 8191)),
+                                        ((16384, 16384), (4095, 4096)),
+                                        ((16382, 16382), (1, 16381))])
+def test_jacobi_in_place_compiles_aliased(one_chip, shape, rows):
     import re
 
     from repro.kernels.stencil_hd.kernel import jacobi_pallas_into
 
-    n = 16384
-    x = jax.ShapeDtypeStruct((n, n), jnp.float32, sharding=one_chip)
+    m, n = shape
+    x = jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
     fn = jax.jit(lambda a, d: jacobi_pallas_into(a, d, rows=rows,
                                                  cols=(1, n - 1)),
                  donate_argnums=1)
     compiled = fn.lower(x, x).compile()
     assert _has_kernel(compiled)
-    assert compiled.memory_analysis().alias_size_in_bytes == 4 * n * n
+    tiles = -(-m // 8) * 8 * -(-n // 128) * 128      # padded to tiles
+    assert compiled.memory_analysis().alias_size_in_bytes == 4 * tiles
     assert not re.search(r"\bcopy\(", compiled.as_text())
+
+
+# the VMEM limit the stencil compiles with, from its block: at every
+# width up to the paper's 24080 columns, in place over the interior and
+# array -> array, well under the 128 MiB of a v5e core, and no larger
+# past the widest block (CPU only: no chip is described)
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("width", [128, 300, 1024, 1025, 4096, 16384,
+                                   24080])
+def test_jacobi_vmem_limit_fits_v5e(dtype, width):
+    from repro.kernels.stencil_hd.kernel import sweep_plan
+
+    def limits(m, n):       # array -> array, and in place
+        return [sweep_plan((m, n), dtype, **region).vmem_bytes()
+                for region in ({}, dict(rows=(1, m - 1), cols=(1, n - 1)))]
+
+    for m in (16384, 4098):
+        for limit, widest in zip(limits(m, width), limits(m, 24080)):
+            assert 16 << 20 <= limit <= widest <= 64 << 20
 
 
 @pytest.mark.parametrize("kv_heads,window", [(32, None), (8, 1024)])
